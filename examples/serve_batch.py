@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_smoke_config
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models import init_cache, init_params
 from repro.train.steps import make_decode_step, make_prefill_step
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if not cfg.supports_decode():

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core import CannyFS, LatencyBackend, LatencyModel, LocalBackend
 from repro.data import Prefetcher, SyntheticLM
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.config import ModelConfig
 from repro.train.loop import LoopConfig, Trainer, run_with_restarts
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--io-latency-ms", type=float, default=1.0,
                     help="simulated remote-storage latency (0 = local)")
     args = ap.parse_args()
+    use_compile_cache()
 
     p = PRESETS[args.preset]
     cfg = ModelConfig(name=f"lm-{args.preset}", family="dense",

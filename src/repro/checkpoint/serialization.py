@@ -10,15 +10,13 @@ import json
 from typing import Any
 
 import jax
+import ml_dtypes
 import numpy as np
 
-try:  # bf16 and friends
-    import ml_dtypes
-    _EXTRA = {"bfloat16": ml_dtypes.bfloat16,
-              "float8_e4m3fn": ml_dtypes.float8_e4m3fn,
-              "float8_e5m2": ml_dtypes.float8_e5m2}
-except ImportError:  # pragma: no cover
-    _EXTRA = {}
+# numpy cannot name these dtypes on its own
+_EXTRA = {"bfloat16": ml_dtypes.bfloat16,
+          "float8_e4m3fn": ml_dtypes.float8_e4m3fn,
+          "float8_e5m2": ml_dtypes.float8_e5m2}
 
 
 def dtype_name(dt) -> str:
@@ -26,9 +24,7 @@ def dtype_name(dt) -> str:
 
 
 def name_to_dtype(name: str):
-    if name in _EXTRA:
-        return np.dtype(_EXTRA[name])
-    return np.dtype(name)
+    return np.dtype(_EXTRA.get(name, name))
 
 
 def leaf_path_str(kp) -> str:

@@ -14,16 +14,22 @@ TPU adaptation notes (vs the CUDA FlashAttention algorithm):
   the structural analogue of FlashAttention's early-exit;
 * GQA shares each kv-head block across its q-head group through the k/v
   index maps (no KV replication in VMEM).
+
+Backward: ``flash_attention_pallas`` is a ``jax.custom_vjp`` whose backward
+is the VJP of the jnp reference (``mha_blocked`` where its 1024-row query
+blocks divide S, else ``mha_ref``), recomputed from the saved q, k, v.  The
+forward runs the kernel; the gradient is the reference's.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import mha_blocked, mha_ref
 
 NEG_INF = -1e30
 LANES = 128
@@ -92,20 +98,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "chunk", "scale", "block_q",
-                     "block_k", "interpret"))
-def flash_attention_pallas(q, k, v, *, causal=True, window=0, chunk=0,
-                           scale=None, block_q=128, block_k=128,
-                           interpret=False):
-    """q (B,S,H,dh); k,v (B,S,K,dh) -> (B,S,H,dh).  Self-attention layout
-    (training / prefill); decode uses the jnp path."""
+def _flash_forward(q, k, v, causal, window, chunk, scale, block_q, block_k,
+                   interpret):
     B, S, H, dh = q.shape
     K = k.shape[2]
     G = H // K
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
-    scale = scale if scale is not None else dh ** -0.5
 
     qt = q.transpose(0, 2, 1, 3)                     # (B,H,S,dh)
     kt = k.transpose(0, 2, 1, 3)                     # (B,K,S,dh)
@@ -137,5 +135,45 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, chunk=0,
             pltpu.VMEM((block_q, dh), jnp.float32),      # output accum
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 10)))
+def _flash(q, k, v, causal, window, chunk, scale, block_q, block_k,
+           interpret):
+    return _flash_forward(q, k, v, causal, window, chunk, scale, block_q,
+                          block_k, interpret)
+
+
+def _flash_fwd(q, k, v, *static):
+    return _flash_forward(q, k, v, *static), (q, k, v)
+
+
+def _flash_bwd(causal, window, chunk, scale, block_q, block_k, interpret,
+               res, g):
+    S = res[0].shape[1]
+    ref = (functools.partial(mha_blocked, block_q=1024) if S % 1024 == 0
+           else mha_ref)
+    _, vjp = jax.vjp(functools.partial(ref, causal=causal, window=window,
+                                       chunk=chunk, scale=scale), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "window", "chunk", "scale", "block_q",
+                     "block_k", "interpret"))
+def flash_attention_pallas(q, k, v, *, causal=True, window=0, chunk=0,
+                           scale=None, block_q=128, block_k=128,
+                           interpret=False):
+    """q (B,S,H,dh); k,v (B,S,K,dh) -> (B,S,H,dh).  Self-attention layout
+    (training / prefill); decode uses the jnp path.  Differentiable
+    (reference backward)."""
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    return _flash(q, k, v, causal, window, chunk, scale, block_q, block_k,
+                  interpret)

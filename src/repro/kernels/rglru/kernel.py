@@ -6,7 +6,13 @@ the (lane-block of the) hidden state resident in VMEM across the whole
 sequence instead of round-tripping HBM per step.  Grid is
 (batch, width-blocks, time-blocks) with time last (sequential); each step
 consumes a (T_blk × 128) tile and runs a fori loop over its rows, state in
-fp32 scratch.  Width is vectorized across the 128-lane dimension.
+fp32 scratch.  Width is vectorized across the 128-lane dimension.  The
+initial state enters as (B, 1, W) so its (1, 128) block spans the array's
+full second-to-last dim.
+
+Backward: ``rglru_pallas`` is a ``jax.custom_vjp`` whose backward is the VJP
+of the jnp reference ``rglru_assoc`` (recomputed from the saved inputs).
+The forward runs the kernel; the gradient is the reference's.
 """
 from __future__ import annotations
 
@@ -17,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ref import rglru_assoc
+
 LANES = 128
 
 
@@ -25,34 +33,22 @@ def _rglru_kernel(la_ref, gx_ref, h0_ref, y_ref, h_scr, *, t_blk):
 
     @pl.when(ti == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)[None, :]
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    la = la_ref[0].astype(jnp.float32)        # (T, 128) log decay
-    gx = gx_ref[0].astype(jnp.float32)        # (T, 128) gated input
-    a = jnp.exp(la)
-    beta = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * la), 1e-12))
-    u = beta * gx
+    def step(t, h):
+        la = la_ref[0, pl.ds(t, 1), :].astype(jnp.float32)   # (1, 128)
+        gx = gx_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        beta = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * la), 1e-12))
+        h = jnp.exp(la) * h + beta * gx
+        y_ref[0, pl.ds(t, 1), :] = h.astype(y_ref.dtype)
+        return h
 
-    def step(t, carry):
-        h, ys = carry
-        h = a[t] * h + u[t]
-        ys = jax.lax.dynamic_update_slice_in_dim(ys, h[None], t, axis=0)
-        return h, ys
-
-    h0 = h_scr[0]
-    h, ys = jax.lax.fori_loop(
-        0, t_blk, step, (h0, jnp.zeros((t_blk, LANES), jnp.float32)))
-    h_scr[...] = h[None]
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_scr[...] = jax.lax.fori_loop(0, t_blk, step, h_scr[...])
 
 
-@functools.partial(jax.jit, static_argnames=("t_blk", "interpret"))
-def rglru_pallas(log_a, gx, h0=None, *, t_blk: int = 128, interpret=False):
-    """log_a, gx (B,S,W) -> (y (B,S,W), h_last (B,W)).  W, S 128-aligned."""
+def _rglru_forward(log_a, gx, h0, t_blk, interpret):
     B, S, W = gx.shape
     assert S % t_blk == 0 and W % LANES == 0, (S, W)
-    if h0 is None:
-        h0 = jnp.zeros((B, W), jnp.float32)
     n_w = W // LANES
     n_t = S // t_blk
 
@@ -63,11 +59,38 @@ def rglru_pallas(log_a, gx, h0=None, *, t_blk: int = 128, interpret=False):
         in_specs=[
             pl.BlockSpec((1, t_blk, LANES), lambda b, w, t: (b, t, w)),
             pl.BlockSpec((1, t_blk, LANES), lambda b, w, t: (b, t, w)),
-            pl.BlockSpec((1, LANES), lambda b, w, t: (b, w)),
+            pl.BlockSpec((1, 1, LANES), lambda b, w, t: (b, 0, w)),
         ],
         out_specs=pl.BlockSpec((1, t_blk, LANES), lambda b, w, t: (b, t, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), gx.dtype),
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.float32)],
         interpret=interpret,
-    )(log_a, gx, h0)
+        name="rglru_scan",
+    )(log_a, gx, h0[:, None, :])
     return y, y[:, -1].astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rglru(log_a, gx, h0, t_blk, interpret):
+    return _rglru_forward(log_a, gx, h0, t_blk, interpret)
+
+
+def _rglru_fwd(log_a, gx, h0, t_blk, interpret):
+    return _rglru_forward(log_a, gx, h0, t_blk, interpret), (log_a, gx, h0)
+
+
+def _rglru_bwd(t_blk, interpret, res, g):
+    _, vjp = jax.vjp(rglru_assoc, *res)
+    return vjp(g)
+
+
+_rglru.defvjp(_rglru_fwd, _rglru_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("t_blk", "interpret"))
+def rglru_pallas(log_a, gx, h0=None, *, t_blk: int = 128, interpret=False):
+    """log_a, gx (B,S,W) -> (y (B,S,W), h_last (B,W)).  W, S 128-aligned;
+    differentiable (reference backward)."""
+    if h0 is None:
+        h0 = jnp.zeros(gx.shape[::2], jnp.float32)
+    return _rglru(log_a, gx, h0, t_blk, interpret)

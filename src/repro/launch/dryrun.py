@@ -40,6 +40,10 @@ from repro.train.steps import (TrainConfig, make_decode_step,
                                make_train_step, serve_shardings,
                                train_shardings)
 
+# the chip the production meshes model (the dry-run itself lowers on host
+# CPU devices, so the roofline is priced at this kind's peaks)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _mem_stats(compiled) -> dict:
     out = {}
@@ -188,6 +192,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     terms = RooflineTerms(
         arch=arch, shape=shape_name, mesh=mesh_name, chips=chips,
+        device_kind=TARGET_DEVICE_KIND,
         flops_per_device=flops, bytes_per_device=bytes_acc,
         coll_bytes_per_device=coll_total, coll_breakdown=coll,
         model_flops=model_flops,

@@ -9,17 +9,38 @@ a partitioned module → × chips for the global figure).  collective_bytes is
 parsed from the compiled HLO text: the operand/result bytes of every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
 
-Hardware constants (per the brief; v5e-class chip):
-    197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks come from ``CHIP_PEAKS``, keyed by ``jax.Device.device_kind``; a
+kind that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: float      # HBM capacity
+    ici_bw: float         # chip-to-chip bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                             ici_bw=1600e9 / 8 / 4),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known: {sorted(CHIP_PEAKS)}") from None
+
 
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
@@ -76,6 +97,7 @@ class RooflineTerms:
     shape: str
     mesh: str
     chips: int
+    device_kind: str
     flops_per_device: float
     bytes_per_device: float
     coll_bytes_per_device: float
@@ -84,16 +106,20 @@ class RooflineTerms:
     peak_memory_per_device: float = 0.0
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes_per_device / ICI_BW
+        return self.coll_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -117,13 +143,13 @@ class RooflineTerms:
         """Fraction of the pure-compute roofline achieved if the step ran at
         the max-term estimate AND all compiled FLOPs were useful model
         FLOPs: (MODEL_FLOPS / chips / peak) / step_time."""
-        ideal = self.model_flops / self.chips / PEAK_FLOPS
+        ideal = self.model_flops / self.chips / self.peaks.flops
         return ideal / self.step_time_s if self.step_time_s else 0.0
 
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "flops_per_device": self.flops_per_device,
             "bytes_per_device": self.bytes_per_device,
             "coll_bytes_per_device": self.coll_bytes_per_device,

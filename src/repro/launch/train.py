@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import CannyFS, LatencyBackend, LatencyModel, LocalBackend
 from repro.data import Prefetcher, SyntheticLM
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.train.loop import LoopConfig, Trainer, run_with_restarts
 from repro.train.steps import TrainConfig
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--dtype", default="float32",
                     choices=("float32", "bfloat16"))
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = {"debug": lambda: make_debug_mesh(),

@@ -7,14 +7,14 @@ Fault model (the paper's, applied to training):
   eagerly ACKed, so the accelerator never stalls on storage latency;
 * a checkpoint is a transaction: COMMIT marker last, rollback of partial
   output, restart from the last committed step;
-* ``run_with_restarts`` is the job harness: on any step-time failure it
-  rolls the engine back, restores the last committed checkpoint (possibly
-  onto a different mesh — elasticity) and continues.
+* ``run_with_restarts`` is the job harness: on an I/O or transaction
+  failure it rolls the engine back, restores the last committed checkpoint
+  (possibly onto a different mesh — elasticity) and continues.
 """
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Iterator, Optional
 
 import jax
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import TransactionalCheckpointManager
-from repro.core import CannyFS
+from repro.core import CannyFS, ProcessKilled, TransactionFailedError
 from repro.models import ModelConfig, init_params
 from repro.optim import init_opt_state
 from repro.train.metrics import MetricsWriter
@@ -88,7 +88,7 @@ class Trainer:
             self.step = int(np.asarray(restored["step"]))
             print(f"[trainer] restored committed checkpoint @ {step_no}")
         except FileNotFoundError:
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 params = jax.jit(
                     lambda k: init_params(k, cfg),
                     out_shardings=sh["params"])(
@@ -116,7 +116,7 @@ class Trainer:
             lr = cosine_with_warmup(jnp.asarray(self.step, jnp.float32),
                                     peak_lr=self.tc.peak_lr,
                                     warmup=lc.warmup, total=lc.total_steps)
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 params, opt, m = self.step_fn(
                     self.state["params"], self.state["opt"], batch, lr)
             self.state = {"params": params, "opt": opt,
@@ -136,9 +136,11 @@ class Trainer:
 
 def run_with_restarts(make_trainer: Callable[[], Trainer], *,
                       max_restarts: int = 2) -> dict:
-    """The job harness: run; on failure, roll back and resubmit (restore
-    from last committed checkpoint).  Matches the paper's transaction
-    retry loop at job granularity."""
+    """The job harness: run; on an I/O or transaction failure, roll back
+    and resubmit (restore from last committed checkpoint).  Matches the
+    paper's transaction retry loop at job granularity.  Any other
+    exception is a program error and propagates on the first attempt, as
+    ``run_transaction`` treats a failing body."""
     attempt = 0
     while True:
         trainer = make_trainer()
@@ -146,7 +148,7 @@ def run_with_restarts(make_trainer: Callable[[], Trainer], *,
             sample = next(trainer.data)
             trainer.init_state(sample)
             return trainer.run()
-        except Exception:
+        except (OSError, TransactionFailedError, ProcessKilled):
             attempt += 1
             trainer.fs.engine.reset_poison()
             trainer.fs.ledger.clear()

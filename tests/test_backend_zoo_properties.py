@@ -14,23 +14,14 @@ existing property contracts (ledgered <= injected; clean runs byte-
 identical) must hold with a cost-modelled backend at the bottom of the
 stack.
 
-Mirrors the driver pattern of ``test_prefetch_properties``: hypothesis
-streams where available, seeded ``random`` fallback trials where not.
+Mirrors the op-stream replay of ``test_prefetch_properties``.
 """
-import random
-
-import pytest
+import hypothesis.strategies as stx
+from hypothesis import HealthCheck, given, settings
 
 from repro.core import (CannyFS, FaultInjectingBackend, FaultPlan,
                         FaultRule, InMemoryBackend, ObjectStoreBackend,
                         ObjectStoreModel, QuotaBackend, RemoteStreamBackend)
-
-try:
-    import hypothesis.strategies as stx
-    from hypothesis import HealthCheck, given, settings
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
 
 # pre-existing state (populated on the oracle, bypassing billing) — gives
 # renames both pre-existing sources (plain copy+delete path) and
@@ -39,9 +30,6 @@ COLD_DIRS = ["pre", "pre/d0", "pre/d1"]
 COLD_FILES = [f"{d}/c{i}" for d in COLD_DIRS for i in range(2)]
 DIRS = COLD_DIRS + ["live"]
 FILES = [f"{d}/f{i}" for d in DIRS for i in range(2)] + COLD_FILES
-
-OPS = ("write", "append", "rename", "unlink", "readdir", "stat", "read",
-       "rmtree", "remake", "chmod")
 
 
 def _make_backend(kind: str):
@@ -64,27 +52,6 @@ def _populate(oracle):
     for f in COLD_FILES:
         oracle.create(f)
         oracle.write_at(f, 0, f.encode())
-
-
-def gen_ops(rng: random.Random, n: int = 24):
-    out = []
-    for _ in range(n):
-        op = rng.choice(OPS)
-        if op in ("write", "append"):
-            out.append((op, rng.choice(FILES),
-                        bytes(rng.randrange(256)
-                              for _ in range(rng.randrange(0, 24)))))
-        elif op == "rename":
-            out.append((op, rng.choice(FILES), rng.choice(FILES)))
-        elif op in ("readdir", "remake", "rmtree"):
-            out.append((op, rng.choice(DIRS), None))
-        elif op == "stat":
-            out.append((op, rng.choice(FILES + DIRS), None))
-        elif op == "chmod":
-            out.append((op, rng.choice(FILES), 0o600))
-        else:   # read / unlink
-            out.append((op, rng.choice(FILES), None))
-    return out
 
 
 def _drive(fs, ops):
@@ -207,60 +174,44 @@ def check_fault_contract(ops, seed):
                 == outcome["remote"][1])
 
 
-if HAVE_HYPOTHESIS:
-    def _op_strategy():
-        payload = stx.binary(min_size=0, max_size=24)
-        write = stx.tuples(stx.sampled_from(["write", "append"]),
-                           stx.sampled_from(FILES), payload)
-        rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
-                            stx.sampled_from(FILES))
-        chmod = stx.tuples(stx.just("chmod"), stx.sampled_from(FILES),
-                           stx.just(0o600))
-        readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
-                             stx.none())
-        statop = stx.tuples(stx.just("stat"),
-                            stx.sampled_from(FILES + DIRS), stx.none())
-        read = stx.tuples(stx.just("read"), stx.sampled_from(FILES),
-                          stx.none())
-        unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
-                            stx.none())
-        rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
-                            stx.none())
-        remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
-                            stx.none())
-        return stx.lists(stx.one_of(write, rename, chmod, readdir, statop,
-                                    read, unlink, rmtree, remake),
-                         min_size=1, max_size=26)
+def _op_strategy():
+    payload = stx.binary(min_size=0, max_size=24)
+    write = stx.tuples(stx.sampled_from(["write", "append"]),
+                       stx.sampled_from(FILES), payload)
+    rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
+                        stx.sampled_from(FILES))
+    chmod = stx.tuples(stx.just("chmod"), stx.sampled_from(FILES),
+                       stx.just(0o600))
+    readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
+                         stx.none())
+    statop = stx.tuples(stx.just("stat"),
+                        stx.sampled_from(FILES + DIRS), stx.none())
+    read = stx.tuples(stx.just("read"), stx.sampled_from(FILES),
+                      stx.none())
+    unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
+                        stx.none())
+    rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
+                        stx.none())
+    remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
+                        stx.none())
+    return stx.lists(stx.one_of(write, rename, chmod, readdir, statop,
+                                read, unlink, rmtree, remake),
+                     min_size=1, max_size=26)
 
-    @settings(max_examples=40, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
-    def test_zoo_backends_execution_identical_to_oracle(ops, workers):
-        check_equivalent(ops, workers)
+@settings(max_examples=40, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
+def test_zoo_backends_execution_identical_to_oracle(ops, workers):
+    check_equivalent(ops, workers)
 
-    @settings(max_examples=15, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
-    def test_zoo_backends_identical_under_quota(ops, workers):
-        check_quota_equivalent(ops, workers)
+@settings(max_examples=15, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
+def test_zoo_backends_identical_under_quota(ops, workers):
+    check_quota_equivalent(ops, workers)
 
-    @settings(max_examples=15, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), seed=stx.integers(0, 3))
-    def test_zoo_backends_honor_fault_contract(ops, seed):
-        check_fault_contract(ops, seed)
-else:
-    @pytest.mark.parametrize("trial", range(120))
-    def test_zoo_backends_execution_identical_to_oracle_random(trial):
-        rng = random.Random(30_000 + trial)
-        check_equivalent(gen_ops(rng), workers=rng.choice([1, 4]))
-
-    @pytest.mark.parametrize("trial", range(40))
-    def test_zoo_backends_identical_under_quota_random(trial):
-        rng = random.Random(40_000 + trial)
-        check_quota_equivalent(gen_ops(rng), workers=rng.choice([1, 4]))
-
-    @pytest.mark.parametrize("trial", range(40))
-    def test_zoo_backends_honor_fault_contract_random(trial):
-        rng = random.Random(50_000 + trial)
-        check_fault_contract(gen_ops(rng), seed=trial % 4)
+@settings(max_examples=15, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), seed=stx.integers(0, 3))
+def test_zoo_backends_honor_fault_contract(ops, seed):
+    check_fault_contract(ops, seed)

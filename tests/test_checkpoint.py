@@ -5,10 +5,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis",
-                    reason="hypothesis not installed (see requirements-dev.txt)")
 import hypothesis.strategies as stx
 from hypothesis import HealthCheck, given, settings
 

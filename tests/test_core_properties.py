@@ -8,10 +8,6 @@ never *what*.
 """
 from __future__ import annotations
 
-import pytest
-
-pytest.importorskip("hypothesis",
-                    reason="hypothesis not installed (see requirements-dev.txt)")
 import hypothesis.strategies as stx
 from hypothesis import HealthCheck, given, settings
 
@@ -184,7 +180,6 @@ def test_error_always_surfaces_by_commit(fail_at, n):
             return super().write_at(p, o, d)
 
     from repro.core import Transaction, TransactionFailedError
-    import pytest
     be = Bad()
     fs = CannyFS(be)
     txn = Transaction(fs)

@@ -18,7 +18,8 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 480) -> str:
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = SRC
     env.pop("REPRO_KERNELS", None)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    code = "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code)
+    out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env,
                          timeout=timeout)
     assert out.returncode == 0, f"stdout:{out.stdout}\nstderr:{out.stderr}"
@@ -29,7 +30,7 @@ def test_flash_decode_matches_ref():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.parallel.flash_decode import seq_sharded_decode_attention
         from repro.kernels.flash_attention.ref import mha_ref
         B, Sc, H, K, dh = 2, 64, 8, 1, 32
@@ -69,8 +70,8 @@ def test_sharded_train_step_matches_single_device():
                  "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 32), 0, 256)}
         tc = TrainConfig(dtype=jnp.float32, remat_policy="none", z_loss=0.0)
         outs = {}
-        for name, mesh in [("multi", jax.make_mesh((4, 2), ("data", "model"))),
-                           ("single", jax.make_mesh((1, 1), ("data", "model")))]:
+        for name, mesh in [("multi", make_mesh((4, 2), ("data", "model"))),
+                           ("single", make_mesh((1, 1), ("data", "model")))]:
             bshape = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
             sh = train_shardings(cfg, mesh, jax.eval_shape(lambda: params), bshape)
             step = jax.jit(make_train_step(cfg, mesh, tc),
@@ -100,7 +101,7 @@ def test_pod_grad_compress_close_to_exact():
         cfg = dataclasses.replace(get_smoke_config("stablelm-3b"),
                                   d_model=128, num_heads=4, num_kv_heads=4,
                                   d_ff=256, vocab_size=256)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         params = init_params(jax.random.PRNGKey(0), cfg)
         opt = init_opt_state(params)
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 256),
@@ -135,7 +136,7 @@ def test_param_spec_rules_cover_all_archs():
     run_with_devices("""
         import jax
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.configs import ARCH_IDS, get_config
         from repro.models import param_specs
         from repro.parallel.sharding import param_pspecs, zero1_specs
@@ -167,7 +168,7 @@ def test_dryrun_cell_mini():
         import jax
         import repro.launch.mesh as M
         M.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2, 2, 4) if multi_pod else (4, 4),
+            make_mesh((2, 2, 4) if multi_pod else (4, 4),
                           ("pod", "data", "model") if multi_pod
                           else ("data", "model")))
         import repro.launch.dryrun as D
@@ -198,7 +199,7 @@ def test_pipeline_parallel_forward_matches_sequential():
         from repro.parallel.pipeline import pp_forward, pp_stage_body
         cfg = dataclasses.replace(get_smoke_config("stablelm-3b"),
                                   num_layers=4, d_model=64)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         params = init_params(jax.random.PRNGKey(0), cfg)
         n_micro, mb, S = 4, 2, 16
         x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, S, cfg.d_model))

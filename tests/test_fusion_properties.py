@@ -1,10 +1,6 @@
 """Fused-vs-unfused oracle property tests (hypothesis): for any op
 stream and any seed, fusion on/off leaves the InMemory backend in the
 identical final state with identical read results and ledger outcomes."""
-import pytest
-
-pytest.importorskip("hypothesis",
-                    reason="hypothesis not installed (see requirements-dev.txt)")
 import hypothesis.strategies as stx
 from hypothesis import HealthCheck, given, settings
 

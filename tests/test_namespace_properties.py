@@ -4,10 +4,6 @@ rmtree, the overlay's whole purpose — running with the overlay enabled
 and disabled leaves the InMemory backend in the identical final state
 with identical read results and ledger outcomes, including under seeded
 fault plans."""
-import pytest
-
-pytest.importorskip("hypothesis",
-                    reason="hypothesis not installed (see requirements-dev.txt)")
 import hypothesis.strategies as stx
 from hypothesis import HealthCheck, given, settings
 

@@ -3,26 +3,13 @@ cold pre-populated tree — walks, readdirs, stats, writes, removals,
 whole-subtree rmtrees — running with the speculative metadata prefetcher
 enabled and disabled leaves the InMemory backend in the identical final
 state with identical read results and ledger outcomes, including under
-seeded fault plans.  Mirrors the fusion/overlay equivalence suites.
-
-Where hypothesis is installed the streams are minimised shrinking
-examples; where it is absent (the satellite's random-driver fallback)
-the same driver runs under seeded ``random`` streams — 150 trials for
-the clean property, 60 for the fault-plan property — so the property is
-exercised either way instead of silently skipping."""
-import random
-
-import pytest
+seeded fault plans.  Mirrors the fusion/overlay equivalence suites;
+the streams are hypothesis's shrinking examples."""
+import hypothesis.strategies as stx
+from hypothesis import HealthCheck, given, settings
 
 from repro.core import (CannyFS, FaultInjectingBackend, FaultPlan,
                         FaultRule, InMemoryBackend)
-
-try:
-    import hypothesis.strategies as stx
-    from hypothesis import HealthCheck, given, settings
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
 
 # the cold tree every run starts from (populated directly on the
 # backend, so the mount must discover it — prefetch's whole domain)
@@ -32,9 +19,6 @@ COLD_FILES = [f"{d}/c{i}" for d in COLD_DIRS for i in range(2)]
 DIRS = ["pre", "pre/d0", "pre/d1", "pre/d0/g0", "live"]
 FILES = [f"{d}/f{i}" for d in DIRS for i in range(2)] + COLD_FILES
 
-OPS = ("walk", "readdir", "stat", "write", "read", "unlink", "rename",
-       "rmtree", "remake")
-
 
 def _populate(be):
     be.mkdir("live")
@@ -43,29 +27,6 @@ def _populate(be):
     for f in COLD_FILES:
         be.create(f)
         be.write_at(f, 0, f.encode())
-
-
-def gen_ops(rng: random.Random, n: int = 22):
-    """One random op stream (the fallback driver's generator; the
-    hypothesis strategy below mirrors it)."""
-    out = []
-    for _ in range(n):
-        op = rng.choice(OPS)
-        if op == "write":
-            out.append((op, rng.choice(FILES),
-                        bytes(rng.randrange(256) for _ in range(
-                            rng.randrange(0, 12)))))
-        elif op == "rename":
-            out.append((op, rng.choice(FILES), rng.choice(FILES)))
-        elif op == "walk":
-            out.append((op, rng.choice(["", "pre"]), None))
-        elif op in ("readdir", "remake", "rmtree"):
-            out.append((op, rng.choice(DIRS), None))
-        elif op == "stat":
-            out.append((op, rng.choice(FILES + DIRS), None))
-        else:   # read / unlink
-            out.append((op, rng.choice(FILES), None))
-    return out
 
 
 def _drive(fs, ops):
@@ -173,48 +134,37 @@ def check_fault_equivalent(ops, seed):
         assert outcome[0][2] == outcome[1][2]
 
 
-if HAVE_HYPOTHESIS:
-    def _op_strategy():
-        write = stx.tuples(stx.just("write"), stx.sampled_from(FILES),
-                           stx.binary(min_size=0, max_size=12))
-        rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
-                            stx.sampled_from(FILES))
-        walk = stx.tuples(stx.just("walk"), stx.sampled_from(["", "pre"]),
-                          stx.none())
-        readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
-                             stx.none())
-        statop = stx.tuples(stx.just("stat"),
-                            stx.sampled_from(FILES + DIRS), stx.none())
-        read = stx.tuples(stx.just("read"), stx.sampled_from(FILES),
-                          stx.none())
-        unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
-                            stx.none())
-        rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
-                            stx.none())
-        remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
-                            stx.none())
-        return stx.lists(stx.one_of(write, rename, walk, readdir, statop,
-                                    read, unlink, rmtree, remake),
-                         min_size=1, max_size=25)
+def _op_strategy():
+    write = stx.tuples(stx.just("write"), stx.sampled_from(FILES),
+                       stx.binary(min_size=0, max_size=12))
+    rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
+                        stx.sampled_from(FILES))
+    walk = stx.tuples(stx.just("walk"), stx.sampled_from(["", "pre"]),
+                      stx.none())
+    readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
+                         stx.none())
+    statop = stx.tuples(stx.just("stat"),
+                        stx.sampled_from(FILES + DIRS), stx.none())
+    read = stx.tuples(stx.just("read"), stx.sampled_from(FILES),
+                      stx.none())
+    unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
+                        stx.none())
+    rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
+                        stx.none())
+    remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
+                        stx.none())
+    return stx.lists(stx.one_of(write, rename, walk, readdir, statop,
+                                read, unlink, rmtree, remake),
+                     min_size=1, max_size=25)
 
-    @settings(max_examples=50, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
-    def test_prefetch_on_and_off_execution_identical(ops, workers):
-        check_equivalent(ops, workers)
+@settings(max_examples=50, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
+def test_prefetch_on_and_off_execution_identical(ops, workers):
+    check_equivalent(ops, workers)
 
-    @settings(max_examples=20, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), seed=stx.integers(0, 3))
-    def test_prefetch_modes_agree_under_fault_plans(ops, seed):
-        check_fault_equivalent(ops, seed)
-else:
-    @pytest.mark.parametrize("trial", range(150))
-    def test_prefetch_on_and_off_execution_identical_random(trial):
-        rng = random.Random(10_000 + trial)
-        check_equivalent(gen_ops(rng), workers=rng.choice([1, 4]))
-
-    @pytest.mark.parametrize("trial", range(60))
-    def test_prefetch_modes_agree_under_fault_plans_random(trial):
-        rng = random.Random(20_000 + trial)
-        check_fault_equivalent(gen_ops(rng), seed=trial % 4)
+@settings(max_examples=20, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), seed=stx.integers(0, 3))
+def test_prefetch_modes_agree_under_fault_plans(ops, seed):
+    check_fault_equivalent(ops, seed)

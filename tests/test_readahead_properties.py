@@ -5,27 +5,14 @@ the read-side data plane enabled (tiny windows, so several are in
 flight per file) and disabled leaves the InMemory backend in the
 identical final state with identical read results and ledger outcomes,
 including under seeded fault plans.  Mirrors the prefetch/fusion/
-overlay equivalence suites.
-
-Where hypothesis is installed the streams are minimised shrinking
-examples; where it is absent (the satellite's random-driver fallback)
-the same driver runs under seeded ``random`` streams — 120 trials for
-the clean property, 50 for the fault-plan property — so the property is
-exercised either way instead of silently skipping."""
-import random
-
-import pytest
+overlay equivalence suites; the streams are hypothesis's shrinking
+examples."""
+import hypothesis.strategies as stx
+from hypothesis import HealthCheck, given, settings
 
 from repro.core import (CannyFS, FaultInjectingBackend, FaultPlan, FaultRule,
                         InMemoryBackend, ReadPolicy, Transaction,
                         TransactionFailedError)
-
-try:
-    import hypothesis.strategies as stx
-    from hypothesis import HealthCheck, given, settings
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
 
 # tiny windows force several speculative fetches per streamed file;
 # a small batch width forces frequent stat_vec flushes
@@ -39,9 +26,6 @@ COLD_FILES = sorted(COLD_SIZES)
 DIRS = ["pre", "live"]
 FILES = COLD_FILES + [f"{d}/f{i}" for d in DIRS for i in range(2)]
 
-OPS = ("stream", "pread", "write", "trunc", "unlink", "rename", "stat",
-       "readdir", "rmtree", "remake", "txn")
-
 
 def _payload(path: str, size: int) -> bytes:
     seed = sum(path.encode())
@@ -54,36 +38,6 @@ def _populate(be):
     for f, size in COLD_SIZES.items():
         be.create(f)
         be.write_at(f, 0, _payload(f, size))
-
-
-def gen_ops(rng: random.Random, n: int = 18):
-    """One random op stream (the fallback driver's generator; the
-    hypothesis strategy below mirrors it)."""
-    out = []
-    for _ in range(n):
-        op = rng.choice(OPS)
-        if op == "stream":
-            out.append((op, rng.choice(FILES), rng.choice([300, 700, 1024])))
-        elif op == "pread":
-            out.append((op, rng.choice(FILES),
-                        (rng.randrange(0, 10000), rng.randrange(0, 1500))))
-        elif op == "write":
-            out.append((op, rng.choice(FILES),
-                        bytes(rng.randrange(256)
-                              for _ in range(rng.randrange(0, 2000)))))
-        elif op == "trunc":
-            out.append((op, rng.choice(FILES), rng.randrange(0, 6000)))
-        elif op == "rename":
-            out.append((op, rng.choice(FILES), rng.choice(FILES)))
-        elif op in ("readdir", "remake", "rmtree"):
-            out.append((op, rng.choice(DIRS), None))
-        elif op == "stat":
-            out.append((op, rng.choice(FILES + DIRS), None))
-        elif op == "txn":
-            out.append((op, rng.choice(DIRS), rng.randrange(2, 6)))
-        else:   # unlink
-            out.append((op, rng.choice(FILES), None))
-    return out
 
 
 def _drive(fs, ops):
@@ -206,54 +160,43 @@ def check_fault_equivalent(ops, seed):
         assert outcome[0][2] == outcome[1][2]
 
 
-if HAVE_HYPOTHESIS:
-    def _op_strategy():
-        stream = stx.tuples(stx.just("stream"), stx.sampled_from(FILES),
-                            stx.sampled_from([300, 700, 1024]))
-        pread = stx.tuples(stx.just("pread"), stx.sampled_from(FILES),
-                           stx.tuples(stx.integers(0, 10000),
-                                      stx.integers(0, 1500)))
-        write = stx.tuples(stx.just("write"), stx.sampled_from(FILES),
-                           stx.binary(min_size=0, max_size=2000))
-        trunc = stx.tuples(stx.just("trunc"), stx.sampled_from(FILES),
-                           stx.integers(0, 6000))
-        rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
-                            stx.sampled_from(FILES))
-        statop = stx.tuples(stx.just("stat"),
-                            stx.sampled_from(FILES + DIRS), stx.none())
-        readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
-                             stx.none())
-        unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
-                            stx.none())
-        rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
-                            stx.none())
-        remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
-                            stx.none())
-        txn = stx.tuples(stx.just("txn"), stx.sampled_from(DIRS),
-                         stx.integers(2, 5))
-        return stx.lists(stx.one_of(stream, pread, write, trunc, rename,
-                                    statop, readdir, unlink, rmtree, remake,
-                                    txn),
-                         min_size=1, max_size=20)
+def _op_strategy():
+    stream = stx.tuples(stx.just("stream"), stx.sampled_from(FILES),
+                        stx.sampled_from([300, 700, 1024]))
+    pread = stx.tuples(stx.just("pread"), stx.sampled_from(FILES),
+                       stx.tuples(stx.integers(0, 10000),
+                                  stx.integers(0, 1500)))
+    write = stx.tuples(stx.just("write"), stx.sampled_from(FILES),
+                       stx.binary(min_size=0, max_size=2000))
+    trunc = stx.tuples(stx.just("trunc"), stx.sampled_from(FILES),
+                       stx.integers(0, 6000))
+    rename = stx.tuples(stx.just("rename"), stx.sampled_from(FILES),
+                        stx.sampled_from(FILES))
+    statop = stx.tuples(stx.just("stat"),
+                        stx.sampled_from(FILES + DIRS), stx.none())
+    readdir = stx.tuples(stx.just("readdir"), stx.sampled_from(DIRS),
+                         stx.none())
+    unlink = stx.tuples(stx.just("unlink"), stx.sampled_from(FILES),
+                        stx.none())
+    rmtree = stx.tuples(stx.just("rmtree"), stx.sampled_from(DIRS),
+                        stx.none())
+    remake = stx.tuples(stx.just("remake"), stx.sampled_from(DIRS),
+                        stx.none())
+    txn = stx.tuples(stx.just("txn"), stx.sampled_from(DIRS),
+                     stx.integers(2, 5))
+    return stx.lists(stx.one_of(stream, pread, write, trunc, rename,
+                                statop, readdir, unlink, rmtree, remake,
+                                txn),
+                     min_size=1, max_size=20)
 
-    @settings(max_examples=40, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
-    def test_readahead_on_and_off_execution_identical(ops, workers):
-        check_equivalent(ops, workers)
+@settings(max_examples=40, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), workers=stx.sampled_from([1, 4]))
+def test_readahead_on_and_off_execution_identical(ops, workers):
+    check_equivalent(ops, workers)
 
-    @settings(max_examples=15, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(ops=_op_strategy(), seed=stx.integers(0, 3))
-    def test_readahead_modes_agree_under_fault_plans(ops, seed):
-        check_fault_equivalent(ops, seed)
-else:
-    @pytest.mark.parametrize("trial", range(120))
-    def test_readahead_on_and_off_execution_identical_random(trial):
-        rng = random.Random(30_000 + trial)
-        check_equivalent(gen_ops(rng), workers=rng.choice([1, 4]))
-
-    @pytest.mark.parametrize("trial", range(50))
-    def test_readahead_modes_agree_under_fault_plans_random(trial):
-        rng = random.Random(40_000 + trial)
-        check_fault_equivalent(gen_ops(rng), seed=trial % 4)
+@settings(max_examples=15, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(ops=_op_strategy(), seed=stx.integers(0, 3))
+def test_readahead_modes_agree_under_fault_plans(ops, seed):
+    check_fault_equivalent(ops, seed)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.core import CannyFS, InMemoryBackend
+from repro.core import CannyFS, InMemoryBackend, ProcessKilled
 from repro.data import Prefetcher, SyntheticLM
 from repro.launch.mesh import make_debug_mesh
 from repro.train.loop import LoopConfig, Trainer, run_with_restarts
@@ -75,7 +75,7 @@ def test_run_with_restarts_recovers_from_crash():
                 # train a bit, checkpoint, then die mid-job
                 super().run(max_steps=10)
                 crashed["done"] = True
-                raise RuntimeError("simulated node failure")
+                raise ProcessKilled("simulated node failure")
             return super().run(max_steps=max_steps)
 
     def factory():
