@@ -31,6 +31,7 @@ import numpy as np
 from repro.core import CannyFS, is_under, norm_path
 from repro.core.durability import commit_marker_ok
 from repro.core.errors import CannyError
+from repro.trace import span
 
 # ledger kinds that cannot be a checkpoint write failure — a failed or
 # cancelled readdir-prefetch stat on the step dir must not condemn a save
@@ -168,11 +169,13 @@ class TransactionalCheckpointManager:
         """Eagerly-ACKed checkpoint save.  Returns as soon as all writes are
         queued (device→host copy included); a background finalizer commits.
         """
-        self.wait_for_save()          # one in-flight checkpoint at a time
+        with span("ckpt.join"):
+            self.wait_for_save()      # one in-flight checkpoint at a time
         t0 = time.monotonic()
         d = self._step_dir(step)
         res = SaveResult(step=step, directory=d)
-        manifest, leaves = flatten_for_save(state)
+        with span("ckpt.serialize"):
+            manifest, leaves = flatten_for_save(state)
         under_d = self._under_dir(d)
 
         def abort_save(e: BaseException) -> SaveResult:
@@ -193,16 +196,19 @@ class TransactionalCheckpointManager:
         # blamed on) whatever user Transaction is open on this mount
         try:
             with self.fs.detached():
-                self.fs.makedirs(d)
+                with span("ckpt.submit"):
+                    self.fs.makedirs(d)
+                    self.fs.write_file(f"{d}/{MANIFEST_FILE}",
+                                       manifest_bytes(manifest))
                 total = 0
-                self.fs.write_file(f"{d}/{MANIFEST_FILE}",
-                                   manifest_bytes(manifest))
                 for key, arr in leaves:
                     fname = key.replace("/", "__") + ".bin"
+                    with span("ckpt.serialize"):
+                        blob = arr.tobytes()
                     # chunked stream: the optimizer coalesces these into
                     # one vectored write_vec per shard file
-                    blob = arr.tobytes()
-                    with self.fs.open(f"{d}/{fname}", "wb") as f:
+                    with span("ckpt.submit"), \
+                            self.fs.open(f"{d}/{fname}", "wb") as f:
                         for lo in range(0, len(blob), _WRITE_CHUNK):
                             f.write(blob[lo:lo + _WRITE_CHUNK])
                     total += arr.nbytes

@@ -154,7 +154,11 @@ Semantics (paper §2–§3):
   ``bulk_removes`` (cross-path removal collapses),
   ``bulk_reverify_promoted``/``bulk_reverify_demoted`` (fused removals
   confirmed / fallen back at execution time), ``steals``/``parks``
-  (dispatch-layer load balancing), ``adaptive_max_bytes`` (the latest
+  (dispatch-layer load balancing), ``eager_ack_s``/``sync_wait_s``
+  (``ack_latency_s`` split into eager ACKs and synchronous waits),
+  ``queue_wait_s`` (ready ops waiting for a worker),
+  ``budget_waits``/``budget_wait_s`` (submitters blocked on the
+  in-flight budget), ``adaptive_max_bytes`` (the latest
   BDP-derived coalescing clamp),
   ``prefetch_{issued,batches,hits,wasted,cancelled}`` (the speculative
   metadata-prefetch pipeline's accounting),
@@ -237,8 +241,15 @@ class EngineStats:
     prefetched_stats: int = 0
     barrier_waits: int = 0
     max_queue_depth: int = 0
-    ack_latency_s: float = 0.0   # total caller-visible latency of eager ops
+    ack_latency_s: float = 0.0   # total caller-visible latency of submit:
+    #                              eager ACKs and synchronous waits alike
+    eager_ack_s: float = 0.0     # ...the eager ops' share of it
+    sync_wait_s: float = 0.0     # ...the synchronous ops' share of it
     exec_latency_s: float = 0.0  # total background execution time
+    queue_wait_s: float = 0.0    # total time executed ops sat ready,
+    #                              waiting for a worker
+    budget_waits: int = 0        # submissions blocked at max_inflight
+    budget_wait_s: float = 0.0   # ...and the time they were blocked
     # -- fusion / optimizer counters --------------------------------------
     fused_writes: int = 0        # write_at calls absorbed into a pending op
     folded_meta: int = 0         # chmod/utimens/truncate last-wins folds
@@ -575,15 +586,19 @@ class EagerIOEngine:
                 with self._adm_lock:
                     self._admitting -= 1
         if eager:
+            dt = time.monotonic() - t0
             self.stats.eager_acks += 1
-            self.stats.ack_latency_s += time.monotonic() - t0
+            self.stats.eager_ack_s += dt
+            self.stats.ack_latency_s += dt
             return None
         self.stats.sync_ops += 1
         if self.sim is not None:
             self.sim.wait_event(op.done)
         else:
             op.done.wait()
-        self.stats.ack_latency_s += time.monotonic() - t0
+        dt = time.monotonic() - t0
+        self.stats.sync_wait_s += dt
+        self.stats.ack_latency_s += dt
         if op.error is not None:
             raise op.error
         return op.result
@@ -890,6 +905,7 @@ class EagerIOEngine:
                 cb()
         with self._sched._ctl:   # exact counters (see scheduler lock note)
             self.stats.exec_latency_s += op.finished_at - op.started_at
+            self.stats.queue_wait_s += op.started_at - op.ready_at
             self.stats.executed += 1
             if op.cancelled:
                 self.stats.cancelled += 1

@@ -161,7 +161,7 @@ class _TenantState:
 class _Op:
     __slots__ = ("seq", "kind", "paths", "fn", "done", "error", "result",
                  "remaining_deps", "dependents", "cancelled", "submitted_at",
-                 "started_at", "finished_at", "eager", "region",
+                 "ready_at", "started_at", "finished_at", "eager", "region",
                  "flock", "completed", "claimed", "sealed", "elided",
                  "payload", "prev_same_path", "wired", "speculative",
                  "tenant")
@@ -183,6 +183,7 @@ class _Op:
         self.dependents: list[_Op] = []
         self.cancelled = False
         self.submitted_at = time.monotonic()
+        self.ready_at = 0.0           # stamped when it enters a ready deque
         self.started_at = 0.0
         self.finished_at = 0.0
         # -- optimizer state (guarded by flock) --
@@ -382,6 +383,7 @@ class OpScheduler:
         over the ACK-time mocked entry).  ``tenant`` scopes the op to a
         registered tenant: its poison gate, its budget slice, its DWRR
         credit."""
+        t_blocked = 0.0   # when the budget first blocked this caller
         while True:
             hooked = False
             shed: Optional[_Op] = None
@@ -407,6 +409,10 @@ class OpScheduler:
                         self.stats.op_counts.get(kind, 0) + 1
                     self.stats.max_queue_depth = max(
                         self.stats.max_queue_depth, self._inflight)
+                    if t_blocked:
+                        self.stats.budget_waits += 1
+                        self.stats.budget_wait_s += (time.monotonic()
+                                                     - t_blocked)
                     break
                 # saturated: shed the oldest queued speculative op before
                 # blocking anyone — advisory lanes degrade, real work
@@ -421,6 +427,8 @@ class OpScheduler:
                         self.stats.admission_sheds += 1
                         self.stats.cancelled += 1
                 if shed is None:
+                    if not t_blocked:
+                        t_blocked = time.monotonic()
                     if tenant is not None:
                         tenant.waiting += 1
                     if self._sim is not None:
@@ -580,6 +588,7 @@ class OpScheduler:
         leaf: never held while taking any other lock).  Speculative ops
         land on the low-priority lane."""
         sh = self._home_shard(op)
+        op.ready_at = time.monotonic()
         with sh.rlock:
             (sh.rq_lo if op.speculative else sh.rq).append(op)
 

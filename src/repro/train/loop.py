@@ -13,7 +13,6 @@ Fault model (the paper's, applied to training):
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Any, Callable, Iterator, Optional
 
@@ -25,6 +24,7 @@ from repro.checkpoint import TransactionalCheckpointManager
 from repro.core import CannyFS, ProcessKilled, TransactionFailedError
 from repro.models import ModelConfig, init_params
 from repro.optim import init_opt_state
+from repro.trace import span
 from repro.train.metrics import MetricsWriter
 from repro.train.steps import TrainConfig, make_train_step, train_shardings
 from repro.optim.schedule import cosine_with_warmup
@@ -110,26 +110,35 @@ class Trainer:
         target = min(self.lc.total_steps,
                      self.step + (max_steps or self.lc.total_steps))
         last_metrics: dict = {}
-        t_start = time.monotonic()
         while self.step < target:
-            batch = self.put_batch(next(self.data))
-            lr = cosine_with_warmup(jnp.asarray(self.step, jnp.float32),
-                                    peak_lr=self.tc.peak_lr,
-                                    warmup=lc.warmup, total=lc.total_steps)
+            # host spans tile the loop body around the step call
+            with span("train.batch"):
+                batch = self.put_batch(next(self.data))
+            with span("train.schedule"):
+                lr = cosine_with_warmup(jnp.asarray(self.step, jnp.float32),
+                                        peak_lr=self.tc.peak_lr,
+                                        warmup=lc.warmup,
+                                        total=lc.total_steps)
+                step_no = jnp.asarray(self.step + 1, jnp.int32)
             with jax.set_mesh(self.mesh):
                 params, opt, m = self.step_fn(
                     self.state["params"], self.state["opt"], batch, lr)
-            self.state = {"params": params, "opt": opt,
-                          "step": jnp.asarray(self.step + 1, jnp.int32)}
+            with span("train.state"):
+                # drops the previous state, whose donated arrays each give
+                # up the GIL as they are freed
+                self.state = {"params": params, "opt": opt, "step": step_no}
             self.step += 1
             if self.step % lc.log_every == 0 or self.step == target:
-                m = {k: float(np.asarray(v)) for k, v in m.items()}
-                m["steps_per_s"] = self.step / (time.monotonic() - t_start)
-                self.metrics.write(self.step, m)
+                with span("train.log"):
+                    m = {k: float(np.asarray(v)) for k, v in m.items()}
+                    self.metrics.write(self.step, m)
                 last_metrics = m
             if self.step % lc.ckpt_every == 0 or self.step == target:
-                res = self.ckpt.save(self.step, jax.device_get(self.state))
-                self.metrics.write(self.step, {"ckpt_ack_s": res.ack_s})
+                with span("train.fetch_state"):
+                    host_state = jax.device_get(self.state)
+                with span("train.save"):
+                    res = self.ckpt.save(self.step, host_state)
+                    self.metrics.write(self.step, {"ckpt_ack_s": res.ack_s})
         self.ckpt.wait_for_save()
         return last_metrics
 

@@ -2,6 +2,7 @@
 checkpoint through CannyFS, a restore into a fresh Trainer on a fresh
 mount, one more step; and which failures the job harness restarts on."""
 import errno
+import time
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,7 @@ from repro.configs import get_smoke_config
 from repro.core import CannyFS, InMemoryBackend
 from repro.data import SyntheticLM
 from repro.launch.mesh import make_debug_mesh
+from repro.trace import spans
 from repro.train.loop import LoopConfig, Trainer, run_with_restarts
 from repro.train.steps import TrainConfig
 
@@ -111,3 +113,40 @@ def test_run_with_restarts_gives_up_after_max_restarts():
     with pytest.raises(OSError):
         run_with_restarts(factory, max_restarts=2)
     assert len(made) == 3
+
+
+def test_trainer_spans_tile_the_loop_and_the_save():
+    tr = make_trainer(InMemoryBackend(), total=3)
+    tr.init_state(next(tr.data))
+    t0 = time.perf_counter()
+    tr.run()                               # 3 steps, then one save
+    t1 = time.perf_counter()
+    got = spans(t0=t0, t1=t1)
+    names = [s.name for s in got]
+    for name in ("train.batch", "train.schedule", "train.state",
+                 "train.log"):
+        assert names.count(name) == 3
+        assert all(s.parent is None for s in got if s.name == name)
+    assert names.count("train.fetch_state") == names.count("train.save") == 1
+    save = next(s for s in got if s.name == "train.save")
+    assert next(s for s in got if s.name == "train.fetch_state").t1 \
+        <= save.t0
+    parts = [s for s in got if s.name.startswith("ckpt.")]
+    assert {s.name for s in parts} == {"ckpt.join", "ckpt.serialize",
+                                       "ckpt.submit"}
+    for s in parts:
+        assert s.parent == "train.save"
+        assert save.t0 <= s.t0 <= s.t1 <= save.t1
+    # one serialize and one submit per leaf, besides the flatten and the
+    # directory with its manifest
+    leaves = len(jax.tree.leaves(tr.state))
+    assert names.count("ckpt.serialize") == names.count("ckpt.submit") \
+        == leaves + 1
+    res = tr.ckpt.results[-1]
+    assert res.ok
+    # ack_s is timed from after the join: the parts lie inside it, and
+    # cover most of it
+    covered = sum(s.t1 - s.t0 for s in parts)
+    assert covered <= res.ack_s + 1e-3
+    assert covered >= 0.5 * res.ack_s
+    tr.fs.close()
