@@ -37,16 +37,17 @@ from repro.trace import span
 # cancelled readdir-prefetch stat on the step dir must not condemn a save
 _READ_KINDS = frozenset({"stat", "readdir", "read", "readlink"})
 
-from .serialization import (flatten_for_save, manifest_bytes, parse_manifest,
-                            unflatten_from)
+from .serialization import (flatten_for_save, leaf_bytes, manifest_bytes,
+                            parse_manifest, unflatten_from)
 
 COMMIT_FILE = "COMMIT"
 MANIFEST_FILE = "manifest.json"
 
 # leaf payloads stream through CannyFile in bounded chunks: consecutive
 # chunks coalesce in the engine's optimizer into one vectored write_vec
-# backend call, so large shards pay one remote roundtrip without the
-# manager ever materializing more than the source array
+# backend call, so large shards pay one remote roundtrip.  Each chunk is a
+# slice of a read-only byte view of the host array, so neither the view
+# nor the chunking copies the leaf
 _WRITE_CHUNK = 4 << 20
 
 
@@ -168,6 +169,12 @@ class TransactionalCheckpointManager:
     def save(self, step: int, state: Any, *, block: bool = False) -> SaveResult:
         """Eagerly-ACKed checkpoint save.  Returns as soon as all writes are
         queued (device→host copy included); a background finalizer commits.
+
+        The leaves' host memory is borrowed, not copied: the queued writes
+        read it through read-only views until the save's commit ends
+        (``wait_for_save``), so the caller must not mutate ``state``'s host
+        arrays before then.  ``Trainer.run`` passes a fresh
+        ``jax.device_get`` tree that nothing else holds.
         """
         with span("ckpt.join"):
             self.wait_for_save()      # one in-flight checkpoint at a time
@@ -204,7 +211,7 @@ class TransactionalCheckpointManager:
                 for key, arr in leaves:
                     fname = key.replace("/", "__") + ".bin"
                     with span("ckpt.serialize"):
-                        blob = arr.tobytes()
+                        blob = leaf_bytes(arr)
                     # chunked stream: the optimizer coalesces these into
                     # one vectored write_vec per shard file
                     with span("ckpt.submit"), \

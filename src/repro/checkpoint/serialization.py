@@ -1,8 +1,12 @@
 """Pytree <-> bytes codec for checkpoints.
 
-Leaves are stored raw (``tobytes``) with dtype/shape in a JSON manifest —
-no pickle, bf16-safe via ml_dtypes, mmap-friendly.  Keys are '/'-joined
-pytree paths so a manifest diff is human-readable.
+Leaves are stored raw (each written from a read-only byte view of its
+host array, ``leaf_bytes``; no copy) with dtype/shape in a JSON manifest —
+no pickle, bf16-safe via ml_dtypes, mmap-friendly.  A leaf whose host array
+lies in memory with its axes in another order than C order (the host copy
+of a device array with a non-default layout) is stored in that order, named
+by the manifest's ``order``, rather than transposed on the host.  Keys are
+'/'-joined pytree paths so a manifest diff is human-readable.
 """
 from __future__ import annotations
 
@@ -48,13 +52,36 @@ def flatten_for_save(tree: Any) -> tuple[dict, list[tuple[str, np.ndarray]]]:
     for kp, leaf in leaves_kp:
         key = leaf_path_str(kp)
         arr = np.asarray(leaf)
-        manifest["leaves"][key] = {
+        meta = manifest["leaves"][key] = {
             "dtype": dtype_name(arr.dtype),
             "shape": list(arr.shape),
             "nbytes": int(arr.nbytes),
         }
+        order = memory_order(arr)
+        if order is not None:
+            meta["order"] = order
+            arr = arr.transpose(order)     # a C-contiguous view, no copy
         out.append((key, arr))
     return manifest, out
+
+
+def memory_order(arr: np.ndarray) -> list[int] | None:
+    """The axes of ``arr`` from the outermost in memory to the innermost,
+    where that is not C order and ``arr`` is dense in it; else None."""
+    if arr.flags.c_contiguous:
+        return None
+    order = sorted(range(arr.ndim), key=lambda i: -arr.strides[i])
+    if not arr.transpose(order).flags.c_contiguous:
+        return None
+    return order
+
+
+def leaf_bytes(arr: np.ndarray) -> memoryview:
+    """A read-only 1-D byte view of ``arr``'s C-order bytes, the same bytes
+    ``arr.tobytes()`` gives.  Copies only a non-contiguous array."""
+    v = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+    v.flags.writeable = False
+    return memoryview(v)
 
 
 def tree_def_of(tree: Any):
@@ -70,7 +97,12 @@ def unflatten_from(manifest: dict, blobs: dict[str, bytes], like: Any):
         key = leaf_path_str(kp)
         meta = manifest["leaves"][key]
         arr = np.frombuffer(blobs[key], dtype=name_to_dtype(meta["dtype"]))
-        arr = arr.reshape(meta["shape"])
+        order = meta.get("order")
+        if order is None:
+            arr = arr.reshape(meta["shape"])
+        else:
+            arr = arr.reshape([meta["shape"][i] for i in order]).transpose(
+                np.argsort(order))
         leaves.append(arr)
     return jax.tree_util.tree_unflatten(treedef, leaves)
 

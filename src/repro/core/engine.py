@@ -158,7 +158,9 @@ Semantics (paper §2–§3):
   (``ack_latency_s`` split into eager ACKs and synchronous waits),
   ``queue_wait_s`` (ready ops waiting for a worker),
   ``budget_waits``/``budget_wait_s`` (submitters blocked on the
-  in-flight budget), ``adaptive_max_bytes`` (the latest
+  in-flight budget), ``write_copied_bytes`` (bytes ``CannyFile.write``
+  froze with ``bytes()`` because the caller's buffer was writable or
+  not contiguous), ``adaptive_max_bytes`` (the latest
   BDP-derived coalescing clamp),
   ``prefetch_{issued,batches,hits,wasted,cancelled}`` (the speculative
   metadata-prefetch pipeline's accounting),
@@ -250,6 +252,8 @@ class EngineStats:
     #                              waiting for a worker
     budget_waits: int = 0        # submissions blocked at max_inflight
     budget_wait_s: float = 0.0   # ...and the time they were blocked
+    write_copied_bytes: int = 0  # CannyFile.write copies of buffers it
+    #                              could not borrow (read-only views are)
     # -- fusion / optimizer counters --------------------------------------
     fused_writes: int = 0        # write_at calls absorbed into a pending op
     folded_meta: int = 0         # chmod/utimens/truncate last-wins folds

@@ -31,6 +31,15 @@ class CannyFile:
     the worker without copying (the user-space analogue of the paper's
     splice-based zero-copy path — we transfer ownership of the `bytes`
     object instead of kernel pipe pages).
+
+    Which buffers travel without a copy is decided by what the caller
+    passes: ``bytes`` and a read-only, C-contiguous ``memoryview`` (cast
+    to unsigned bytes) are borrowed as they are — the caller promises not
+    to change the memory behind a read-only view until the op has run.
+    Any other buffer (a ``bytearray``, a writable ``memoryview``) could
+    change after the ACK, so it is frozen with ``bytes()`` at the call, as
+    is a non-contiguous view, and the copy is counted in
+    ``EngineStats.write_copied_bytes``.
     """
 
     def __init__(self, fs: "CannyFS", path: str, mode: str):
@@ -50,12 +59,18 @@ class CannyFile:
                 fs.create(self.path)
 
     # -- write side --
-    def write(self, data: bytes) -> int:
+    def write(self, data) -> int:
         if self.mode == "rb":
             raise IOError("file opened read-only")
         if self._closed:
             raise ValueError("I/O on closed file")
-        data = bytes(data)  # freeze caller's view; engine takes ownership
+        if type(data) is not bytes:
+            if (isinstance(data, memoryview) and data.readonly
+                    and data.c_contiguous):
+                data = data.cast("B")   # borrowed: len() counts bytes
+            else:
+                data = bytes(data)      # freeze what cannot be borrowed
+                self.fs.engine.stats.write_copied_bytes += len(data)
         off = self._offset
         self._offset += len(data)
         self.fs._write_at(self.path, off, data)

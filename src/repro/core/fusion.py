@@ -133,7 +133,8 @@ class WritePayload:
 
     Contiguous appends extend the previous segment as a chunk list —
     concatenation is deferred to ``segments()`` at execution time, so the
-    hot ACK path never copies payload bytes.  Mutated only under the
+    hot ACK path never copies payload bytes; chunks that are borrowed
+    views are never concatenated.  Mutated only under the
     owning op's ``flock`` (scheduler guarantee); frozen once claimed."""
 
     __slots__ = ("_segs", "nbytes")
@@ -156,8 +157,20 @@ class WritePayload:
         return len(self._segs)
 
     def segments(self) -> list[tuple[int, bytes]]:
-        return [(off, chunks[0] if len(chunks) == 1 else b"".join(chunks))
-                for off, chunks, _ in self._segs]
+        out = []
+        for off, chunks, _ in self._segs:
+            if len(chunks) == 1:
+                out.append((off, chunks[0]))
+            elif all(type(c) is bytes for c in chunks):
+                out.append((off, b"".join(chunks)))
+            else:
+                # borrowed views (CannyFile.write) go out as consecutive
+                # segments: joining them would copy, and bytes.join holds
+                # the GIL while it copies anything but bytes
+                for c in chunks:
+                    out.append((off, c))
+                    off += len(c)
+        return out
 
 
 class MetaPayload:

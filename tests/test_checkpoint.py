@@ -7,10 +7,14 @@ import jax.numpy as jnp
 import numpy as np
 
 import hypothesis.strategies as stx
+import ml_dtypes
+import pytest
 from hypothesis import HealthCheck, given, settings
 
 from repro.checkpoint import (COMMIT_FILE, TransactionalCheckpointManager)
-from repro.core import CannyFS, InMemoryBackend, LatencyBackend, LatencyModel
+from repro.checkpoint.serialization import parse_manifest
+from repro.core import (CannyFS, FaultInjectingBackend, FaultPlan, FaultRule,
+                        InMemoryBackend, LatencyBackend, LatencyModel)
 
 
 def make_fs(backend=None):
@@ -84,6 +88,97 @@ def test_failed_save_rolls_back_and_next_succeeds():
     assert mgr.results[-1].ok and mgr.list_steps() == [2]
     step, out = mgr.restore(state)
     assert step == 2
+    fs.close()
+
+
+def mixed_state():
+    """fp32 larger than one 4 MB write chunk, bf16, a 0-d int32, and three
+    leaves that are not C-contiguous: a transposed one, one laid out with
+    its last two axes swapped (the host copy of a device array whose
+    layout is major-to-minor (0, 2, 1)) and a strided slice."""
+    rng = np.random.default_rng(0)
+    swapped = rng.standard_normal((3, 5, 4)).astype(np.float32)
+    return {
+        "big": rng.standard_normal(5 << 18).astype(np.float32),   # 5 MB
+        "bf16": rng.standard_normal((7, 3)).astype(ml_dtypes.bfloat16),
+        "step": np.asarray(11, np.int32),
+        "t": rng.standard_normal((6, 4)).astype(np.float32).T,
+        "swapped": swapped.transpose(0, 2, 1),
+        "strided": rng.standard_normal((4, 6)).astype(np.float32)[:, ::2],
+    }
+
+
+def test_mixed_leaves_restore_byte_identically():
+    fs = make_fs()
+    mgr = TransactionalCheckpointManager(fs, "ck")
+    state = mixed_state()
+    assert not any(state[k].flags.c_contiguous
+                   for k in ("t", "swapped", "strided"))
+    mgr.save(5, state, block=True)
+    assert mgr.results[-1].ok
+    assert mgr.results[-1].bytes == sum(a.nbytes for a in state.values())
+    _, out = mgr.restore(state)
+    for k, a in state.items():
+        assert out[k].dtype == a.dtype and out[k].shape == a.shape
+        assert out[k].tobytes() == a.tobytes()
+    fs.close()
+
+
+def test_leaf_is_stored_in_its_memory_order():
+    """A dense leaf whose axes lie in memory in another order than C order
+    is written as it lies, its order named in the manifest; a strided
+    leaf is written in C order."""
+    fs = make_fs()
+    mgr = TransactionalCheckpointManager(fs, "ck")
+    state = mixed_state()
+    mgr.save(5, state, block=True)
+    d = mgr._step_dir(5)
+    leaves = parse_manifest(fs.read_file(f"{d}/manifest.json"))["leaves"]
+    assert leaves["swapped"]["order"] == [0, 2, 1]
+    assert leaves["t"]["order"] == [1, 0]
+    assert all("order" not in leaves[k]
+               for k in ("big", "bf16", "step", "strided"))
+    assert fs.read_file(f"{d}/swapped.bin") == \
+        state["swapped"].transpose(0, 2, 1).tobytes()
+    assert fs.read_file(f"{d}/strided.bin") == state["strided"].tobytes()
+    fs.close()
+
+
+def test_save_copies_no_leaf_at_the_facade():
+    fs = make_fs()
+    mgr = TransactionalCheckpointManager(fs, "ck")
+    before = fs.stats.write_copied_bytes
+    mgr.save(1, mixed_state(), block=True)
+    assert mgr.results[-1].ok
+    assert fs.stats.write_copied_bytes == before
+    fs.close()
+
+
+@pytest.mark.parametrize("rule", [
+    FaultRule(error="EIO", ops=("write",), path_glob="*big.bin",
+              max_failures=1),
+    FaultRule(outcome="short", ops=("write",), path_glob="*big.bin",
+              short_fraction=0.5, max_failures=1),
+], ids=["eio", "torn"])
+def test_write_fault_rolls_the_save_back(rule):
+    inner = InMemoryBackend()
+    plan = FaultPlan([rule])
+    fs = CannyFS(FaultInjectingBackend(inner, plan), max_inflight=1000,
+                 workers=8, echo_errors=False)
+    mgr = TransactionalCheckpointManager(fs, "ck")
+    state = mixed_state()
+    res = mgr.save(1, state, block=True)
+    assert plan.injected == 1
+    assert not res.ok and "big.bin" in res.error
+    assert mgr.list_steps() == []
+    assert all("step_" not in p for p in inner.snapshot()["files"])
+    assert not fs.ledger
+    # the fault has expired: the next save commits and reads back
+    res = mgr.save(2, state, block=True)
+    assert res.ok and mgr.list_steps() == [2]
+    _, out = mgr.restore(state)
+    for k, a in state.items():
+        assert out[k].tobytes() == a.tobytes()
     fs.close()
 
 

@@ -379,6 +379,82 @@ def test_diverted_stream_match_is_elided():
     assert be.read_at("out/a.bin", 0, -1) == bytes(before)
 
 
+def test_diverted_stream_of_read_only_views_is_elided():
+    """The re-run writes its stream as borrowed read-only slices (as the
+    checkpoint manager does): they are buffered, assembled and verified
+    against the recorded checksums like bytes, and elided."""
+    content = b"alpha" * 64
+    be = InMemoryBackend()
+    be.mkdir("out")
+    be.create("out/a.bin")
+    be.write_at("out/a.bin", 0, content)
+    _forge_spill(
+        be,
+        {"t": "begin", "e": 0},
+        {"t": "done", "e": 0, "k": "mkdir", "p": ["out"]},
+        {"t": "jrnl", "e": 0, "p": "out", "d": 1},
+        {"t": "done", "e": 0, "k": "create", "p": ["out/a.bin"]},
+        {"t": "jrnl", "e": 0, "p": "out/a.bin", "d": 0},
+        {"t": "done", "e": 0, "k": "write", "p": ["out/a.bin"],
+         "segs": [[0, len(content), _crc(content)]]})
+
+    fs = CannyFS(be, flags=EagerFlags(flush=False), echo_errors=False)
+    fs.resume(".spill")
+    view = memoryview(content)
+    with Transaction(fs):
+        fs.mkdir("out")
+        with fs.open("out/a.bin", "wb") as f:
+            for lo in range(0, len(view), 100):
+                f.write(view[lo:lo + 100])
+    fs.close()
+    assert fs.engine.stats.resume_elided_ops >= 3
+    assert fs.engine.stats.write_copied_bytes == 0
+    assert be.read_at("out/a.bin", 0, -1) == content
+
+
+_VIEWED = bytes(range(256)) * 8
+
+
+def _view_body(fs):
+    """``_body``'s shape with a file streamed from read-only slices."""
+    fs.mkdir("out")
+    view = memoryview(_VIEWED)
+    with fs.open("out/v.bin", "wb") as f:
+        for lo in range(0, len(view), 256):
+            f.write(view[lo:lo + 256])
+    fs.mkdir("out/sub")
+    fs.write_file("out/sub/c.bin", b"gamma" * 16)
+
+
+def test_kill_resume_of_a_view_stream_converges():
+    be = InMemoryBackend()
+    plan = FaultPlan([FaultRule(ops=("write", "write_vec"),
+                                path_glob="out/sub/*", outcome="kill",
+                                max_failures=1)], seed=3)
+    fb = FaultInjectingBackend(be, plan)
+    fs = CannyFS(fb, flags=EagerFlags(flush=False), echo_errors=False)
+    fs.enable_spill(".spill")
+    with pytest.raises(ProcessKilled):
+        run_transaction(fs, _view_body, retries=3)
+    assert plan.kills == 1
+    try:
+        fs.close()
+    except Exception:
+        pass
+
+    fb.revive()
+    fs2 = CannyFS(fb, flags=EagerFlags(flush=False), echo_errors=False)
+    assert fs2.resume(".spill")["resumable"]
+    run_transaction(fs2, _view_body)
+    fs2.close()
+    assert fs2.engine.stats.resume_elided_ops > 0
+    assert fs2.engine.stats.write_copied_bytes == 0
+    files, dirs = _data(be)
+    assert files == {"out/v.bin": _VIEWED, "out/sub/c.bin": b"gamma" * 16}
+    assert dirs == {"out", "out/sub"}
+    assert not be.stat(".spill/journal.log").exists
+
+
 def test_stale_tail_truncated_on_load():
     """Bytes past the last parsable record (a torn chunk) are physically
     truncated at load so the resumed epoch appends to a clean prefix."""
